@@ -1,0 +1,7 @@
+"""Time the restoring thread waits for leaves' bytes (``xdfs.restore.fetch``),
+per cold start of the window, in s."""
+from harness.program_spans import FETCH, per_event, window_records
+
+
+def read(run):
+    return per_event(window_records(run), FETCH, run.facts.get("cold_starts"))

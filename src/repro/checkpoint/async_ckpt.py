@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from concurrent.futures import Future
 from typing import Any, List, Optional
 
@@ -17,6 +18,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import xdfs_ckpt
+from repro.core import spans
 
 
 class AsyncCheckpointer:
@@ -33,7 +35,8 @@ class AsyncCheckpointer:
             item = self._q.get()
             if item is None:
                 return
-            tree, step, fut = item
+            tree, step, fut, t_put = item
+            spans.record("ckpt.queued", t_put, time.perf_counter())
             try:
                 fut.set_result(
                     xdfs_ckpt.save(tree, self.directory, step, self.keep_last)
@@ -43,10 +46,12 @@ class AsyncCheckpointer:
 
     def save(self, tree: Any, step: int) -> Future:
         """Non-blocking: snapshot to host, enqueue for the disk thread."""
-        host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), tree)
+        with spans.span("ckpt.snapshot") as sp:
+            host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), tree)
+            sp.nbytes = sum(x.nbytes for x in jax.tree.leaves(host_tree))
         fut: Future = Future()
         self._futures.append(fut)
-        self._q.put((host_tree, step, fut))
+        self._q.put((host_tree, step, fut, time.perf_counter()))
         return fut
 
     def wait(self):

@@ -48,6 +48,7 @@ import jax
 import numpy as np
 
 from repro.cluster.wire import ClusterError
+from repro.core import spans
 from repro.core.header import ProtocolError
 
 log = logging.getLogger(__name__)
@@ -62,16 +63,18 @@ def _session(root: Path, integrity: bool = False):
     """A loopback xDFS session rooted at ``root`` (server + client pair)."""
     from repro.core.api import XdfsClient, XdfsServer
 
-    srv = XdfsServer(engine=ENGINE, root=str(root)).start()
-    cli = XdfsClient.connect(
-        srv.address, n_channels=N_CHANNELS, engine=ENGINE, block_size=BLOCK,
-        integrity=integrity,
-    )
+    with spans.span("session.open"):
+        srv = XdfsServer(engine=ENGINE, root=str(root)).start()
+        cli = XdfsClient.connect(
+            srv.address, n_channels=N_CHANNELS, engine=ENGINE, block_size=BLOCK,
+            integrity=integrity,
+        )
     try:
         yield cli
     finally:
-        cli.close()
-        srv.stop()
+        with spans.span("session.close"):
+            cli.close()
+            srv.stop()
 
 
 def _leaf_files(tree):
@@ -81,6 +84,30 @@ def _leaf_files(tree):
         name = f"leaf_{i:05d}.bin"
         out.append((jax.tree_util.keystr(path), name, leaf))
     return out
+
+
+def _serialize(keypath: str, fname: str, leaf) -> tuple:
+    """A leaf's bytes and its manifest entry."""
+    with spans.span("ckpt.serialize") as sp:
+        arr = np.asarray(jax.device_get(leaf))
+        raw = arr.tobytes()
+        sp.nbytes = len(raw)
+    with spans.span("ckpt.crc", len(raw)):
+        crc = zlib.crc32(raw) & 0xFFFFFFFF
+    return raw, {"key": keypath, "file": fname, "shape": list(arr.shape),
+                 "dtype": str(arr.dtype), "crc32": crc}
+
+
+def _place(meta: dict, raw: bytes, sh):
+    """Check a restored leaf's bytes against the manifest, then put it on
+    the device."""
+    with spans.span("restore.crc", len(raw)):
+        ok = (zlib.crc32(raw) & 0xFFFFFFFF) == meta["crc32"]
+    if not ok:
+        raise IOError(f"checksum mismatch in {meta['file']}")
+    with spans.span("restore.place", len(raw)):
+        arr = np.frombuffer(raw, dtype=meta["dtype"]).reshape(meta["shape"])
+        return jax.device_put(arr, sh) if sh is not None else jax.device_put(arr)
 
 
 def _step_prefix(directory: str, step: int) -> str:
@@ -121,24 +148,18 @@ def _save_cluster(tree: Any, directory: str, step: int, keep_last: int,
     prefix = _step_prefix(directory, step)
     manifest = {"step": step, "leaves": []}
     for keypath, fname, leaf in _leaf_files(tree):
-        arr = np.asarray(jax.device_get(leaf))
-        raw = arr.tobytes()
-        manifest["leaves"].append(
-            {
-                "key": keypath,
-                "file": fname,
-                "shape": list(arr.shape),
-                "dtype": str(arr.dtype),
-                "crc32": zlib.crc32(raw) & 0xFFFFFFFF,
-            }
-        )
-        cluster.put(f"{prefix}/{fname}", data=raw)
+        raw, meta = _serialize(keypath, fname, leaf)
+        manifest["leaves"].append(meta)
+        with spans.span("ckpt.send", len(raw)):
+            cluster.put(f"{prefix}/{fname}", data=raw)
     # manifest LAST = the commit point (restore ignores manifest-less steps)
-    cluster.put(f"{prefix}/manifest.json",
-                data=json.dumps(manifest).encode())
-    for old in _cluster_steps(directory, cluster)[:-keep_last]:
-        for name in cluster.list(_step_prefix(directory, old) + "/"):
-            cluster.delete(name)
+    with spans.span("ckpt.send"):
+        cluster.put(f"{prefix}/manifest.json",
+                    data=json.dumps(manifest).encode())
+    with spans.span("ckpt.finalize"):
+        for old in _cluster_steps(directory, cluster)[:-keep_last]:
+            for name in cluster.list(_step_prefix(directory, old) + "/"):
+                cluster.delete(name)
     return prefix
 
 
@@ -158,12 +179,10 @@ def _restore_one_cluster(directory: str, step: int, like: Any,
         )
     out = []
     for meta, sh in zip(manifest["leaves"], sh_leaves):
-        raw = cluster.get(f"{prefix}/{meta['file']}")
-        if (zlib.crc32(raw) & 0xFFFFFFFF) != meta["crc32"]:
-            raise IOError(f"checksum mismatch in {meta['file']}")
-        arr = np.frombuffer(raw, dtype=meta["dtype"]).reshape(meta["shape"])
-        out.append(jax.device_put(arr, sh) if sh is not None
-                   else jax.device_put(arr))
+        with spans.span("restore.fetch") as sp:
+            raw = cluster.get(f"{prefix}/{meta['file']}")
+            sp.nbytes = len(raw)
+        out.append(_place(meta, raw, sh))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -202,35 +221,29 @@ def save(tree: Any, directory: str, step: int, keep_last: int = 3,
         # flight and the one being prepared are materialized)
         prev = None
         for keypath, fname, leaf in _leaf_files(tree):
-            arr = np.asarray(jax.device_get(leaf))
-            raw = arr.tobytes()
-            manifest["leaves"].append(
-                {
-                    "key": keypath,
-                    "file": fname,
-                    "shape": list(arr.shape),
-                    "dtype": str(arr.dtype),
-                    "crc32": zlib.crc32(raw) & 0xFFFFFFFF,
-                }
-            )
+            raw, meta = _serialize(keypath, fname, leaf)
+            manifest["leaves"].append(meta)
             fut = cli.put(None, f"{rel}/{fname}", data=raw, resume=resume)
             if prev is not None:
-                prev.result()
+                with spans.span("ckpt.send"):
+                    prev.result()
             prev = fut
-        if prev is not None:
-            prev.result()
-        cli.put(None, f"{rel}/manifest.json",
-                data=json.dumps(manifest).encode(), resume=resume).result()
-    # integrity puts keep resume sidecars next to the data files; a fully
-    # landed step no longer needs them, so don't commit them
-    from repro.core.resume import SIDECAR_SUFFIX
+        with spans.span("ckpt.send"):
+            if prev is not None:
+                prev.result()
+            cli.put(None, f"{rel}/manifest.json",
+                    data=json.dumps(manifest).encode(), resume=resume).result()
+    with spans.span("ckpt.finalize"):
+        # integrity puts keep resume sidecars next to the data files; a
+        # fully landed step no longer needs them, so don't commit them
+        from repro.core.resume import SIDECAR_SUFFIX
 
-    for sc in tmp.glob("*" + SIDECAR_SUFFIX):
-        sc.unlink()
-    if final.exists():  # re-save after fault recovery: replace the old step
-        shutil.rmtree(final)
-    os.replace(tmp, final)  # atomic commit
-    _gc(base, keep_last)
+        for sc in tmp.glob("*" + SIDECAR_SUFFIX):
+            sc.unlink()
+        if final.exists():  # re-save after fault recovery: replace the old step
+            shutil.rmtree(final)
+        os.replace(tmp, final)  # atomic commit
+        _gc(base, keep_last)
     return str(final)
 
 
@@ -320,11 +333,11 @@ def _restore_one(d: Path, like: Any, shardings: Any):
         raise ValueError(
             f"leaf count mismatch: ckpt {len(manifest['leaves'])} vs {len(leaves_like)}"
         )
-    def finish(meta, raw, sh):
-        if (zlib.crc32(raw) & 0xFFFFFFFF) != meta["crc32"]:
-            raise IOError(f"checksum mismatch in {meta['file']}")
-        arr = np.frombuffer(raw, dtype=meta["dtype"]).reshape(meta["shape"])
-        return jax.device_put(arr, sh) if sh is not None else jax.device_put(arr)
+    def finish(meta, fut, sh):
+        with spans.span("restore.fetch") as sp:
+            raw = fut.result().data
+            sp.nbytes = len(raw)
+        return _place(meta, raw, sh)
 
     out = []
     with _session(d) as cli:
@@ -334,8 +347,8 @@ def _restore_one(d: Path, like: Any, shardings: Any):
         for meta, sh in zip(manifest["leaves"], sh_leaves):
             nxt = cli.get_bytes(meta["file"])
             if fut is not None:
-                out.append(finish(prev[0], fut.result().data, prev[1]))
+                out.append(finish(prev[0], fut, prev[1]))
             fut, prev = nxt, (meta, sh)
         if fut is not None:
-            out.append(finish(prev[0], fut.result().data, prev[1]))
+            out.append(finish(prev[0], fut, prev[1]))
     return jax.tree_util.tree_unflatten(treedef, out)

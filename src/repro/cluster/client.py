@@ -32,6 +32,7 @@ from repro.cluster.wire import (
     ClusterMsg,
     block_name,
 )
+from repro.core import spans
 from repro.core.api import SessionPool
 from repro.core.faults import RetryPolicy
 from repro.core.integrity import block_crc
@@ -74,7 +75,7 @@ class ClusterClient:
             durability=durability)
         self._owns_pool = pool is None
         self.stats: Dict[str, int] = {
-            "puts": 0, "gets": 0, "blocks_written": 0, "blocks_read": 0,
+            "puts": 0, "gets": 0, "blocks_written": 0,
             "replica_failovers": 0, "degraded_blocks": 0, "replans": 0,
             "busy_retries": 0, "disk_full_refusals": 0,
         }
@@ -204,7 +205,8 @@ class ClusterClient:
     def get(self, name: str) -> bytes:
         """Reassemble ``name`` from block replicas, verifying per-block
         CRCs and failing over dead/corrupt replicas."""
-        meta = self._call(ClusterMsg.LOOKUP, {"name": name})
+        with spans.span("cluster.lookup"):
+            meta = self._call(ClusterMsg.LOOKUP, {"name": name})
         out = bytearray(meta["size"])
         # first pass: one preferred replica per block, fanned out as
         # pipelined futures grouped by session
@@ -266,7 +268,6 @@ class ClusterClient:
             return None
         if len(data) != blk["length"] or _crc(data) != blk["crc32"]:
             return None  # corrupt replica: as dead as a downed node
-        self.stats["blocks_read"] += 1
         return data
 
     # -- namespace ---------------------------------------------------------

@@ -124,10 +124,8 @@ class DataNode:
         self._stop = threading.Event()
         self.errors: deque = deque(maxlen=ERROR_BUFFER)
         self.stats: Dict[str, int] = {
-            "heartbeats": 0, "replicated_out": 0, "dropped": 0,
-            "command_errors": 0, "reregisters": 0, "fenced_commands": 0,
-            "errors_dropped": 0, "scrub_passes": 0, "scrub_corrupt": 0,
-            "sidecars_swept": 0,
+            "heartbeats": 0, "dropped": 0, "reregisters": 0,
+            "fenced_commands": 0, "errors_dropped": 0, "scrub_corrupt": 0,
         }
 
     @property
@@ -141,7 +139,7 @@ class DataNode:
         self.root.mkdir(parents=True, exist_ok=True)
         # a crashed transfer leaves orphan sidecars and atomic-commit temp
         # files; no session is live at startup, so sweeping is safe
-        self.stats["sidecars_swept"] += len(sweep_sidecars(str(self.root)))
+        sweep_sidecars(str(self.root))
         self.server.start()
         self.register()
         if self.auto_heartbeat:
@@ -218,7 +216,6 @@ class DataNode:
         """One deterministic scrub pass; condemned block ids feed the
         next heartbeat (and stay condemned until the drop lands)."""
         report = self.scrubber.scrub_once()
-        self.stats["scrub_passes"] += 1
         for path in report.corrupt + report.missing:
             name = os.path.basename(path)
             if name.startswith(BLOCK_PREFIX) and name.endswith(BLOCK_SUFFIX):
@@ -267,7 +264,6 @@ class DataNode:
                 self._execute(cmd)
             except Exception as e:  # noqa: BLE001 - a failed copy must not
                 # kill the beat loop; the MetaNode replans after the grace
-                self.stats["command_errors"] += 1
                 self._note_error(e)
         return cmds
 
@@ -303,7 +299,6 @@ class DataNode:
         try:
             cli = self.pool.lease(addr)
             cli.put(str(path), block_name(block_id)).result()
-            self.stats["replicated_out"] += 1
         except Exception:
             self.pool.invalidate(addr)
             raise

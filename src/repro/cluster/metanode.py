@@ -188,9 +188,9 @@ class MetaNode:
         self.meta_id = meta_id or f"meta-{id(self) & 0xFFFF:04x}"
         self.stats: Dict[str, int] = {
             "heartbeats": 0, "plans": 0, "commits": 0, "lookups": 0,
-            "re_replications": 0, "rebalance_moves": 0, "nodes_died": 0,
+            "re_replications": 0, "rebalance_moves": 0,
             "journal_records": 0, "snapshots": 0, "replayed_records": 0,
-            "syncs_served": 0, "syncs_applied": 0, "promotions": 0,
+            "promotions": 0,
             "errors_dropped": 0, "corrupt_reported": 0,
             "full_nodes_avoided": 0,
         }
@@ -561,7 +561,6 @@ class MetaNode:
                 if self.journal is not None:
                     self.journal.write_snapshot(snap)
                     self._records_since_snapshot = 0
-                self.stats["syncs_applied"] += 1
             for seq, tag, body in reply.get("records", ()):
                 if seq <= self.seq:
                     continue  # duplicate tail overlap: already applied
@@ -569,7 +568,6 @@ class MetaNode:
                     self.journal.append(seq, tag, body)
                 self._apply(tag, body)
                 self.seq = seq
-                self.stats["syncs_applied"] += 1
             got = reply.get(EPOCH_FIELD)
             if isinstance(got, int) and got > self.epoch:
                 self.epoch = got
@@ -582,7 +580,6 @@ class MetaNode:
         self._require_leader()
         since = int(body.get("since", 0))
         with self._lock:
-            self.stats["syncs_served"] += 1
             if since > self.seq:
                 # the poller is ahead of us (it promoted and wrote its
                 # own records while we were deposed): full resync
@@ -720,7 +717,6 @@ class MetaNode:
         duplicate copies."""
         with self._lock:
             newly_dead = self.detector.sweep()
-            self.stats["nodes_died"] += len(newly_dead)
             alive = self.detector.alive() & set(self.nodes)
             now = self._clock()
             grace = REPLICATION_GRACE_TIMEOUTS * self.heartbeat_timeout

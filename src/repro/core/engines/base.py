@@ -43,6 +43,7 @@ import socket
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
+from repro.core import spans
 from repro.core.header import (
     CRC_TRAILER,
     FLAG_BLOCK_CRC,
@@ -903,9 +904,14 @@ class Sink:
         views (registered pool memory) go into the syscall untouched.
 
         Returns the number of vectored syscalls issued (the seek-reduction
-        metric from the paper)."""
+        metric from the paper). Each call is one ``xdfs.io.write`` span
+        carrying the bytes it writes."""
         if not blocks or (self._fd < 0 and self._cap is None):
             return 0
+        with spans.span("io.write", sum(len(mv) for _, mv in blocks)):
+            return self._writev(blocks)
+
+    def _writev(self, blocks: List[Tuple[int, memoryview]]) -> int:
         if self._cap is not None:
             for off, mv in blocks:
                 self._cap[off : off + len(mv)] = mv

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import get_config
+from repro.core import spans
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.transformer import build_model
@@ -28,25 +29,29 @@ def generate(cfg, mesh, params, prompts, gen_tokens: int, greedy: bool = True,
         prefill = jax.jit(mp.prefill)
         decode = jax.jit(md.decode_step)
 
-        logits, caches = prefill(params, {"inputs": prompts})
+        # the first call builds the program (traced, lowered, compiled or
+        # loaded from the compile cache) before it runs
+        with spans.span("serve.prefill"):
+            logits, caches = prefill(params, {"inputs": prompts})
         s = prompts.shape[1]
         out = []
         key = key if key is not None else jax.random.key(0)
-        for t in range(gen_tokens):
-            if greedy:
-                nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            else:
-                key, sub = jax.random.split(key)
-                nxt = jax.random.categorical(sub, logits[:, -1]).astype(jnp.int32)
-            out.append(nxt)
-            step_in = nxt[:, None]
-            if cfg.frontend:  # stub frontend: embed via a fixed projection
-                step_in = jnp.zeros(
-                    (prompts.shape[0], 1, cfg.d_model), jnp.bfloat16
+        with spans.span("serve.decode"):
+            for t in range(gen_tokens):
+                if greedy:
+                    nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                else:
+                    key, sub = jax.random.split(key)
+                    nxt = jax.random.categorical(sub, logits[:, -1]).astype(jnp.int32)
+                out.append(nxt)
+                step_in = nxt[:, None]
+                if cfg.frontend:  # stub frontend: embed via a fixed projection
+                    step_in = jnp.zeros(
+                        (prompts.shape[0], 1, cfg.d_model), jnp.bfloat16
+                    )
+                logits, caches = decode(
+                    params, {"inputs": step_in, "caches": caches, "pos": jnp.int32(s + t)}
                 )
-            logits, caches = decode(
-                params, {"inputs": step_in, "caches": caches, "pos": jnp.int32(s + t)}
-            )
         return jnp.stack(out, axis=1)
 
 
