@@ -3,6 +3,8 @@ async disk thread."""
 import json
 import os
 import shutil
+import threading
+import weakref
 from pathlib import Path
 
 import jax
@@ -10,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.checkpoint import xdfs_ckpt
+from repro.checkpoint import async_ckpt, xdfs_ckpt
 from repro.checkpoint.async_ckpt import AsyncCheckpointer
 
 
@@ -88,6 +90,107 @@ def test_async_checkpointer(tmp_path):
     assert all(f.done() and f.exception() is None for f in futs)
     assert xdfs_ckpt.latest_step(str(tmp_path)) == 2
     ck.close()
+
+
+def _host_tree(seed=0):
+    rng = np.random.default_rng(seed)
+    return {
+        "a": rng.standard_normal((33, 17), dtype=np.float32),
+        "b": {"w": np.arange(128, dtype=np.int32)},
+        "step": np.float32(7.5),
+    }
+
+
+def _spy_copies(monkeypatch):
+    """Weak references to the device copies ``save`` takes, one list per
+    save, and the copies' buffer pointers."""
+    saves, ptrs = [], []
+    real_copy, real_save = async_ckpt._copy, AsyncCheckpointer.save
+
+    def save(self, tree, step):
+        saves.append([])
+        return real_save(self, tree, step)
+
+    def copy(x):
+        c = real_copy(x)
+        if isinstance(c, jax.Array):
+            saves[-1].append(weakref.ref(c))
+            ptrs.append(c.unsafe_buffer_pointer())
+        return c
+
+    monkeypatch.setattr(async_ckpt, "_copy", copy)
+    monkeypatch.setattr(AsyncCheckpointer, "save", save)
+    return saves, ptrs
+
+
+def _on_device(refs):
+    """Whether a device copy of ``refs`` still holds its device memory."""
+    return any(r() is not None and not r().is_deleted() for r in refs)
+
+
+@pytest.mark.parametrize("make", [_tree, _host_tree], ids=["device", "host"])
+def test_async_save_survives_a_step_that_donates_the_state(make, tmp_path, monkeypatch):
+    """The snapshot is the checkpoint's own: the step after ``save`` may
+    donate the state, and the committed step still restores bit for bit to
+    the state at the call. The device copy is gone once the save is done."""
+    state = make()
+    like = jax.eval_shape(lambda: state)
+    want = [np.array(x) for x in jax.tree.leaves(state)]
+    state_ptrs = {x.unsafe_buffer_pointer() for x in jax.tree.leaves(state)
+                  if isinstance(x, jax.Array)}
+    saves, snap_ptrs = _spy_copies(monkeypatch)
+    ck = AsyncCheckpointer(str(tmp_path))
+    fut = ck.save(state, 1)
+    step = jax.jit(lambda t: jax.tree.map(lambda x: x * 2 + 1, t), donate_argnums=0)
+    jax.block_until_ready(step(state))
+    assert all(x.is_deleted() for x in jax.tree.leaves(state) if isinstance(x, jax.Array))
+    fut.result(timeout=60)
+    ck.close()
+    assert len(snap_ptrs) == len(state_ptrs)
+    assert not state_ptrs & set(snap_ptrs)
+    assert not _on_device(saves[0])
+    restored, s = xdfs_ckpt.restore(str(tmp_path), like)
+    assert s == 1
+    for a, b in zip(jax.tree.leaves(restored), want):
+        a = np.asarray(a)
+        assert a.dtype == b.dtype and a.shape == b.shape and a.tobytes() == b.tobytes()
+
+
+def test_queued_saves_hold_one_snapshot_on_the_device(tmp_path, monkeypatch):
+    """While commits lag behind saves, queued saves wait on the host: at
+    most one save's device copy exists, and the save after that one waits
+    for its transfer."""
+    saves, _ = _spy_copies(monkeypatch)
+    gate = threading.Event()
+    real_save = xdfs_ckpt.save
+
+    def slow_save(*a, **k):
+        assert gate.wait(timeout=60)
+        return real_save(*a, **k)
+
+    monkeypatch.setattr(xdfs_ckpt, "save", slow_save)
+    ck = AsyncCheckpointer(str(tmp_path), depth=2)
+    # one save in the disk thread, two in its queue, one held by the copy
+    # thread, one on the device: no commit ends while the gate is shut
+    futs = []
+    for i in range(5):
+        futs.append(ck.save(_tree(i), i))
+        assert sum(_on_device(refs) for refs in saves) <= 1
+    sixth = threading.Thread(target=lambda: futs.append(ck.save(_tree(5), 5)))
+    sixth.start()
+    sixth.join(timeout=0.5)
+    assert sixth.is_alive() and len(futs) == 5
+    gate.set()
+    sixth.join(timeout=60)
+    assert [f.result(timeout=60) for f in futs] == [
+        str(tmp_path / f"step_{i:08d}") for i in range(6)]
+    ck.close()
+    assert not any(_on_device(refs) for refs in saves)
+    like = jax.eval_shape(lambda: _tree())
+    for i in (3, 4, 5):
+        restored, _ = xdfs_ckpt.restore(str(tmp_path), like, step=i)
+        for a, b in zip(jax.tree.leaves(restored), jax.tree.leaves(_tree(i))):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
 def test_restore_missing_raises(tmp_path):

@@ -127,7 +127,8 @@ def test_save_and_restore_record_their_spans(where, tmp_path, request):
         ck.save(tree, 3).result(timeout=60)
         ck.close()
         restored, _ = xdfs_ckpt.restore(str(tmp_path / "ckpt"), like)
-        want = SAVE_SPANS | RESTORE_SPANS | {"ckpt.snapshot", "ckpt.queued", "io.write"}
+        want = SAVE_SPANS | RESTORE_SPANS | {"ckpt.snapshot", "ckpt.d2h", "ckpt.queued",
+                                             "io.write"}
     else:
         cli = request.getfixturevalue("store")
         xdfs_ckpt.save(tree, "ckpt", step=3, cluster=cli)
@@ -143,5 +144,6 @@ def test_save_and_restore_record_their_spans(where, tmp_path, request):
         assert nbytes[name] == _nbytes(tree), name
     if where == "local":
         assert nbytes["ckpt.snapshot"] == _nbytes(tree)
+        assert nbytes["ckpt.d2h"] == _nbytes(tree)
         # the server's writes hold every leaf and the manifest
         assert nbytes["io.write"] > _nbytes(tree)
