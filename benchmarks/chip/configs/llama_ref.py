@@ -2,9 +2,10 @@
 SmolLM-135M (HF ``LlamaForCausalLM``): token embedding, per layer RMSNorm,
 grouped-query attention with rotary embeddings (half-split, as HF's
 ``rotate_half``) and a causal mask, RMSNorm, SwiGLU MLP, a final RMSNorm and
-the output head tied to the embedding. Training is mean token
-cross-entropy, clipping by global norm and AdamW with decoupled weight
-decay, as the configuration file states them.
+the output head tied to the embedding. Training is that of
+``harness/reference.py`` over these logits, as the configuration file states
+its optimizer. ``train_flops`` counts a training step's operations and
+``TINY`` holds the sizes of the CPU rehearsals.
 
 Straight ``jax.numpy`` in float32 with every product at ``HIGHEST``
 precision: no kernel, cache or batching. It imports nothing of the program
@@ -17,15 +18,18 @@ on operands rounded to int8 with one absmax scale per tensor.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-HI = jax.lax.Precision.HIGHEST
+from harness import flops
+from harness.reference import dot, rms, rope, train_readings as _train_readings
+
 MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 NORMS = ("attn_norm", "mlp_norm")
+# the configuration's sizes in the CPU rehearsals; the rest as published
+TINY = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "vocab_size": 256}
 
 
 # --------------------------------------------------------------- weights
@@ -60,57 +64,7 @@ def init(key, m: dict, param_dtype=jnp.bfloat16) -> dict:
     return out
 
 
-# ------------------------------------------------------------- products
-
-
-def _fake_int8(x):
-    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 127.0
-    return jnp.clip(jnp.round(x / s), -127, 127) * s
-
-
-@functools.lru_cache(maxsize=None)
-def _int8_einsum(spec: str):
-    def plain(a, b):
-        return jnp.einsum(spec, a, b, precision=HI)
-
-    @jax.custom_vjp
-    def f(a, b):
-        return plain(_fake_int8(a), _fake_int8(b))
-
-    def fwd(a, b):
-        qa, qb = _fake_int8(a), _fake_int8(b)
-        return plain(qa, qb), (qa, qb)
-
-    def bwd(res, g):
-        _, vjp = jax.vjp(plain, *res)
-        return vjp(_fake_int8(g))
-
-    f.defvjp(fwd, bwd)
-    return f
-
-
-def _dot(spec: str, a, b, mode: str):
-    if mode == "int8":
-        return _int8_einsum(spec)(a, b)
-    return jnp.einsum(spec, a, b, precision=HI)
-
-
 # --------------------------------------------------------------- forward
-
-
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
-
-
-def _rope(x, theta):
-    """x: (S, H, D); rotary embedding at positions 0..S-1."""
-    s, _, d = x.shape
-    half = d // 2
-    inv = theta ** (-jnp.arange(0, half, dtype=jnp.float32) * 2 / d)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv  # (S, half)
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
 def hidden(p: dict, toks, m: dict, mode: str = "f32"):
@@ -123,110 +77,53 @@ def hidden(p: dict, toks, m: dict, mode: str = "f32"):
 
     @jax.checkpoint
     def layer(x, lp):
-        a = _rms(x, lp["attn_norm"], eps)
-        q = _dot("sd,de->se", a, lp["wq"], mode).reshape(s, h, hd)
-        k = _dot("sd,de->se", a, lp["wk"], mode).reshape(s, kv, hd)
-        v = _dot("sd,de->se", a, lp["wv"], mode).reshape(s, kv, hd)
-        q, k = _rope(q, theta), _rope(k, theta)
+        a = rms(x, lp["attn_norm"], eps)
+        q = dot("sd,de->se", a, lp["wq"], mode).reshape(s, h, hd)
+        k = dot("sd,de->se", a, lp["wk"], mode).reshape(s, kv, hd)
+        v = dot("sd,de->se", a, lp["wv"], mode).reshape(s, kv, hd)
+        q, k = rope(q, theta), rope(k, theta)
         k, v = jnp.repeat(k, h // kv, axis=1), jnp.repeat(v, h // kv, axis=1)
-        sc = _dot("qhd,khd->hqk", q, k, mode) / np.sqrt(hd)
+        sc = dot("qhd,khd->hqk", q, k, mode) / np.sqrt(hd)
         sc = jnp.where(causal[None], sc, -jnp.inf)
-        o = _dot("hqk,khd->qhd", jax.nn.softmax(sc, axis=-1), v, mode)
-        x = x + _dot("se,ed->sd", o.reshape(s, h * hd), lp["wo"], mode)
-        b = _rms(x, lp["mlp_norm"], eps)
-        g = _dot("sd,df->sf", b, lp["w_gate"], mode)
-        u = _dot("sd,df->sf", b, lp["w_up"], mode)
-        x = x + _dot("sf,fd->sd", jax.nn.silu(g) * u, lp["w_down"], mode)
+        o = dot("hqk,khd->qhd", jax.nn.softmax(sc, axis=-1), v, mode)
+        x = x + dot("se,ed->sd", o.reshape(s, h * hd), lp["wo"], mode)
+        b = rms(x, lp["mlp_norm"], eps)
+        g = dot("sd,df->sf", b, lp["w_gate"], mode)
+        u = dot("sd,df->sf", b, lp["w_up"], mode)
+        x = x + dot("sf,fd->sd", jax.nn.silu(g) * u, lp["w_down"], mode)
         return x, None
 
     layers = {k: p[k] for k in MATRICES + NORMS}
     x, _ = jax.lax.scan(layer, x, layers)
-    return _rms(x, p["final_norm"], eps)
+    return rms(x, p["final_norm"], eps)
 
 
 def logits(p: dict, toks, m: dict, mode: str = "f32"):
     """Logits (S, V) of one sequence; ``p`` in float32."""
-    return _dot("sd,vd->sv", hidden(p, toks, m, mode), p["embed"], mode)
-
-
-def _ce_sum(p, inputs, labels, m, mode):
-    lg = logits(p, inputs, m, mode)
-    lz = jax.nn.logsumexp(lg, axis=-1)
-    gold = jnp.take_along_axis(lg, labels[:, None], axis=-1)[:, 0]
-    return jnp.sum(lz - gold)
-
-
-@functools.partial(jax.jit, static_argnames=("mode", "mkey"))
-def _seq_value_grad(p, inputs, labels, *, mode, mkey):
-    return jax.value_and_grad(_ce_sum)(p, inputs, labels, dict(mkey), mode)
-
-
-def loss_and_grads(p32: dict, batch: dict, m: dict, mode: str = "f32",
-                   grad_rows: int = 0):
-    """Mean token cross-entropy of ``batch`` and its gradient, one
-    sequence at a time. ``grad_rows`` > 0 takes the gradient of the first
-    ``grad_rows`` sequences alone (a fault: a worker whose gradient was
-    never exchanged)."""
-    mkey = tuple(sorted((k, v) for k, v in m.items() if not isinstance(v, (list, dict))))
-    rows, n = batch["labels"].shape[0], batch["labels"].size
-    g_rows = grad_rows or rows
-    total, grads = 0.0, None
-    for i in range(rows):
-        l, g = _seq_value_grad(p32, batch["inputs"][i], batch["labels"][i],
-                               mode=mode, mkey=mkey)
-        total = total + l
-        if i < g_rows:
-            grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
-    return total / n, jax.tree.map(lambda g: g * (rows / g_rows) / n, grads)
-
-
-# -------------------------------------------------------------- training
-
-
-def _adamw_step(p, g, mstate, vstate, t, opt):
-    """One AdamW step after clipping ``g`` by its global norm. Parameters
-    keep their stored dtype: the update is rounded to it, then added."""
-    norm = jnp.sqrt(sum(jnp.sum(x * x) for x in jax.tree.leaves(g)))
-    scale = jnp.minimum(1.0, opt["max_grad_norm"] / jnp.maximum(norm, 1e-12))
-    g = jax.tree.map(lambda x: x * scale, g)
-    b1, b2 = opt["b1"], opt["b2"]
-    c1, c2 = 1 - b1 ** t, 1 - b2 ** t
-    mstate = jax.tree.map(lambda mm, x: b1 * mm + (1 - b1) * x, mstate, g)
-    vstate = jax.tree.map(lambda vv, x: b2 * vv + (1 - b2) * x * x, vstate, g)
-
-    def upd(w, mm, vv):
-        delta = (mm / c1) / (jnp.sqrt(vv / c2) + opt["eps"]) \
-            + opt["weight_decay"] * w.astype(jnp.float32)
-        u = (-opt["lr"] * delta).astype(w.dtype)
-        return (w.astype(jnp.float32) + u.astype(jnp.float32)).astype(w.dtype)
-
-    return jax.tree.map(upd, p, mstate, vstate), g, mstate, vstate
-
-
-def leaf_norms(tree) -> dict:
-    return {k: float(jnp.linalg.norm(jnp.asarray(v, jnp.float32).ravel()))
-            for k, v in tree.items()}
+    return dot("sd,vd->sv", hidden(p, toks, m, mode), p["embed"], mode)
 
 
 def train_readings(p0: dict, batches: list, m: dict, opt: dict,
                    mode: str = "f32", grad_rows: int = 0) -> dict:
-    """What the checks compare, for ``len(batches)`` steps from ``p0``
-    (stored dtypes): each step's loss, each leaf's norm of the first
-    clipped gradient, and each leaf's norm of the change of the parameters
-    over all the steps."""
-    p = dict(p0)
-    mstate = jax.tree.map(lambda w: jnp.zeros(w.shape, jnp.float32), p)
-    vstate = jax.tree.map(lambda w: jnp.zeros(w.shape, jnp.float32), p)
-    losses, g1 = [], None
-    for t, batch in enumerate(batches, start=1):
-        p32 = jax.tree.map(lambda w: w.astype(jnp.float32), p)
-        loss, g = loss_and_grads(p32, batch, m, mode, grad_rows)
-        del p32
-        losses.append(float(loss))
-        p, gc, mstate, vstate = _adamw_step(p, g, mstate, vstate, t, opt)
-        if g1 is None:
-            g1 = leaf_norms(gc)
-        del g, gc
-    change = leaf_norms({k: p[k].astype(jnp.float32) - p0[k].astype(jnp.float32)
-                         for k in p})
-    return {"losses": losses, "grad_norms": g1, "change_norms": change}
+    """``harness.reference.train_readings`` over this architecture's logits."""
+    return _train_readings(logits, p0, batches, m, opt, mode, grad_rows)
+
+
+# ----------------------------------------------------------------- count
+
+
+def matmul_params(m: dict) -> int:
+    """Parameters that take part in a matrix product per token: the
+    attention and MLP projections of every layer and the output head
+    (the tied embedding counts once, as the head)."""
+    d, h, kv, hd, f, L, V = dims(m)
+    per_layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
+    return L * per_layer + d * V
+
+
+def train_flops(m: dict, batch: int, seq: int) -> float:
+    """A training step's operations (recomputation not counted): 6 N D for
+    the matrix products plus causal attention."""
+    d, h, kv, hd, f, L, V = dims(m)
+    return (flops.matmul_flops(matmul_params(m), batch * seq)
+            + flops.causal_attention_flops(batch, seq, L, h, hd, hd))

@@ -1,6 +1,11 @@
 """The program under test, as the drivers reach it: its model configuration
 built from a configuration file, its parameter tree filled with the
 benchmark's own weights, and that tree read back in the reference's layout.
+
+What differs between architectures comes from the configuration's layout
+(``drivers/layouts/<layout>.py``, ``Cell.layout()``): ``PATHS``, each
+reference leaf's path in the program's tree, and ``fields(model)``, the
+``ModelConfig`` fields that the configuration's ``model`` keys set.
 """
 from __future__ import annotations
 
@@ -15,32 +20,12 @@ from repro.configs.base import get_config
 from repro.launch.mesh import make_local_mesh
 from repro.optim import AdamW
 
-# reference name -> path in the program's tree (one block kind, scanned)
-_BLOCK = "b0_g"
-_PATHS = {
-    "embed": ("embed",), "final_norm": ("final_norm",),
-    "attn_norm": ("blocks", _BLOCK, "norm1"), "wq": ("blocks", _BLOCK, "wq"),
-    "wk": ("blocks", _BLOCK, "wk"), "wv": ("blocks", _BLOCK, "wv"),
-    "wo": ("blocks", _BLOCK, "wo"), "mlp_norm": ("blocks", _BLOCK, "norm2"),
-    "w_gate": ("blocks", _BLOCK, "mlp", "w_gate"),
-    "w_up": ("blocks", _BLOCK, "mlp", "w_in"),
-    "w_down": ("blocks", _BLOCK, "mlp", "w_out"),
-}
 
-
-def model_config(conf: dict):
+def model_config(conf: dict, layout):
     """The program's ``ModelConfig`` with every size the file states."""
-    m, dep = conf["model"], conf["deployment"]
     base = get_config(conf["program_arch"])
-    return dataclasses.replace(
-        base,
-        num_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        num_heads=m["num_attention_heads"], num_kv_heads=m["num_key_value_heads"],
-        head_dim=m.get("head_dim") or m["hidden_size"] // m["num_attention_heads"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        rope_theta=m["rope_theta"], norm_eps=m["rms_norm_eps"],
-        act=m["hidden_act"], tie_embeddings=m["tie_word_embeddings"],
-        fsdp=dep.get("fsdp", base.fsdp))
+    return dataclasses.replace(base, **layout.fields(conf["model"]),
+                               fsdp=conf["deployment"].get("fsdp", base.fsdp))
 
 
 def optimizer(conf: dict) -> AdamW:
@@ -53,9 +38,9 @@ def mesh(chips: int):
     return make_local_mesh(chips, 1)
 
 
-def to_program(ref: dict) -> dict:
+def to_program(layout, ref: dict) -> dict:
     out: dict = {}
-    for name, path in _PATHS.items():
+    for name, path in layout.PATHS.items():
         node = out
         for k in path[:-1]:
             node = node.setdefault(k, {})
@@ -63,9 +48,9 @@ def to_program(ref: dict) -> dict:
     return out
 
 
-def from_program(tree: dict) -> dict:
+def from_program(layout, tree: dict) -> dict:
     out = {}
-    for name, path in _PATHS.items():
+    for name, path in layout.PATHS.items():
         node = tree
         for k in path:
             node = node[k]
@@ -73,11 +58,11 @@ def from_program(tree: dict) -> dict:
     return out
 
 
-def check_layout(model, ref_like: dict) -> None:
+def check_layout(layout, model, ref_like: dict) -> None:
     """The program's parameters have exactly the reference's shapes and
     stored dtypes, leaf for leaf."""
     want = jax.tree.map(lambda x: (tuple(x.shape), jnp.dtype(x.dtype)),
-                        to_program(ref_like))
+                        to_program(layout, ref_like))
     have = jax.tree.map(lambda x: (tuple(x.shape), jnp.dtype(x.dtype)),
                         model.abstract())
     if want != have:

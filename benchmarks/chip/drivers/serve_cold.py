@@ -49,19 +49,20 @@ class Replica:
         cell = run.cell
         self.run, self.conf, self.traffic = run, cell.config, cell.traffic
         self.m = self.conf["model"]
-        self.ref = cell.reference()
-        self.cfg = program.model_config(self.conf)
+        self.ref, self.layout = cell.reference(), cell.layout()
+        self.cfg = program.model_config(self.conf, self.layout)
         self.mesh = program.mesh(cell.chips)
         model = build_model(self.cfg, self.mesh, "prefill")
         self.dtype = jnp.dtype(self.conf["deployment"]["param_dtype"])
-        program.check_layout(model, jax.eval_shape(
+        program.check_layout(self.layout, model, jax.eval_shape(
             lambda: self.ref.init(jax.random.key(0), self.m, self.dtype)))
         self.sh = program.param_shardings(model)
         t = self.traffic
         self._prompts = jax.jit(lambda k, i: data.prompts(
             jax.random.fold_in(k, i), t["requests"], t["prompt"], self.m["vocab_size"]))
-        self._make = jax.jit(lambda k: program.to_program(self.ref.init(k, self.m, self.dtype)),
-                             out_shardings=self.sh)
+        self._make = jax.jit(
+            lambda k: program.to_program(self.layout, self.ref.init(k, self.m, self.dtype)),
+            out_shardings=self.sh)
         self.reseed(seed)
 
     def reseed(self, seed):

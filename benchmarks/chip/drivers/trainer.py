@@ -36,9 +36,9 @@ class Trainer:
         cell = run.cell
         self.conf, self.traffic = cell.config, cell.traffic
         self.m = self.conf["model"]
-        self.ref = cell.reference()
+        self.ref, self.layout = cell.reference(), cell.layout()
         self.B, self.S = self.traffic["batch"], self.traffic["seq"]
-        self.cfg = program.model_config(self.conf)
+        self.cfg = program.model_config(self.conf, self.layout)
         self.mesh = program.mesh(cell.chips)
         ring = self.traffic.get("sync", "xla") == "ring"
         self.model = build_model(self.cfg, self.mesh, "train", plain=ring)
@@ -60,11 +60,12 @@ class Trainer:
             data.train_batch, batch=self.B, seq=self.S, vocab=self.m["vocab_size"]),
             out_shardings=in_sh)
         self.param_dtype = jnp.dtype(self.conf["deployment"]["param_dtype"])
-        program.check_layout(self.model, jax.eval_shape(
+        program.check_layout(self.layout, self.model, jax.eval_shape(
             lambda: self.ref.init(jax.random.key(0), self.m, self.param_dtype)))
 
         def make(key):
-            params = program.to_program(self.ref.init(key, self.m, self.param_dtype))
+            params = program.to_program(self.layout,
+                                        self.ref.init(key, self.m, self.param_dtype))
             return TrainState(params, self.opt.init(params), jnp.zeros((), jnp.int32))
         self._make = jax.jit(make, out_shardings=self.ss)
         self.tokens_per_step = self.B * self.S
@@ -91,8 +92,8 @@ class Trainer:
     def first_steps(self) -> dict:
         """Steps 1..n from the seed: the program's numbers for the checks."""
         n = self.traffic["check_steps"]
-        norms = jax.jit(lambda t: _norms(program.from_program(t)))
-        names = sorted(program.from_program(self.state.params))
+        norms = jax.jit(lambda t: _norms(program.from_program(self.layout, t)))
+        names = sorted(program.from_program(self.layout, self.state.params))
         p0 = jax.tree.map(jnp.copy, self.state.params)
         losses, grads = [], None
         for i in range(n):

@@ -1,11 +1,14 @@
 """One run of one cell: find its files by name, drive it, print the line.
 
 A cell (``workloads`` in ``BENCHMARK.json``) names a configuration and a
-traffic mix. The configuration's ``file`` holds its sizes; the traffic mix is
-``traffic/<traffic>.json`` and names the driver (``drivers/<driver>.py``)
-that runs it with those parameters. Each per-layer metric is read by
-``metrics/<name>.py``. A later cell, mix or metric is a new file and a new
-entry, with no edit to a file that is here.
+traffic mix. The configuration's ``file`` holds its sizes and names two
+modules of its architecture: its plain reference, ``configs/<reference>.py``
+(the mathematics, ``train_flops`` and the ``TINY`` sizes of the CPU
+rehearsals), and its layout in the program, ``drivers/layouts/<layout>.py``.
+The traffic mix is ``traffic/<traffic>.json`` and names the driver
+(``drivers/<driver>.py``) that runs it with those parameters. Each per-layer
+metric is read by ``metrics/<name>.py``. A later architecture, cell, mix or
+metric is a new file and a new entry, with no edit to a file that is here.
 """
 from __future__ import annotations
 
@@ -51,6 +54,10 @@ class Cell:
     def reference(self):
         return load_module(self.root / "configs" / f"{self.config['reference']}.py",
                            f"bench_ref_{self.config['reference']}")
+
+    def layout(self):
+        return load_module(self.root / "drivers" / "layouts" / f"{self.config['layout']}.py",
+                           f"bench_layout_{self.config['layout']}")
 
 
 def _applies(metric: dict, cell: str) -> bool:

@@ -1,30 +1,45 @@
 """The yardstick's arithmetic: FLOPs, peaks, checksums, gaps, reference."""
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from harness import checks, data, device, flops
+from harness import checks, core, data, device, reference
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 SMOLLM = json.loads((CONFIGS / "smollm-135m-train.json").read_text())["model"]
 
 
 def test_flops_match_a_hand_count_for_smollm_135m():
+    ref = _ref()
     # per layer: q 576x576, k and v 576x192, o 576x576, MLP 3 x 576x1536
     per_layer = 576 * 576 * 2 + 576 * 192 * 2 + 3 * 576 * 1536
     n = 30 * per_layer + 576 * 49152          # head tied to the embedding
-    assert flops.matmul_params(SMOLLM) == n == 134_479_872
+    assert ref.matmul_params(SMOLLM) == n == 134_479_872
     dense = 6 * n * 8 * 2048                  # 13.2 TFLOP
     # causal QK^T and PV, forward 2 * 2 * S^2/2 * 64 per head and sequence,
     # backward twice that: 3.48 TFLOP
     attn = 3 * 2 * 2 * (2048 ** 2 / 2) * 64 * 9 * 8 * 30
     assert dense == pytest.approx(13.22e12, rel=1e-3)
     assert attn == pytest.approx(3.479e12, rel=1e-3)
-    assert flops.train_step_flops(SMOLLM, 8, 2048) == pytest.approx(dense + attn)
+    assert ref.train_flops(SMOLLM, 8, 2048) == pytest.approx(dense + attn)
+
+
+@pytest.mark.parametrize("name", ["smollm-135m.train-ckpt", "smollm-135m.dp4-ring"])
+def test_step_mfu_reads_the_smollm_count_as_before(name):
+    # 16,698,832,846,848 FLOP per 8 x 2048 step: the count step_mfu read
+    # from harness/flops.py before each architecture's count moved to its
+    # reference; equal to the last bit
+    cell = core.find_cell(name)
+    reader = core.load_module(core.HERE / "metrics" / "step_mfu.py", "bench_metric_step_mfu")
+    kind = SimpleNamespace(device_kind="TPU v5 lite")
+    run = SimpleNamespace(cell=cell, window_s=2.0, devices=[kind] * cell.chips,
+                          facts={"steps": 3, "model": SMOLLM, "batch": 8, "seq": 2048})
+    assert reader.read(run) == 100 * (16698832846848.0 * 3) / (2.0 * cell.chips * 197e12)
 
 
 def test_peaks_refuse_an_unknown_device_kind():
@@ -87,7 +102,7 @@ def test_reference_blocks_do_not_change_the_mathematics():
                      ref.init(jax.random.key(1), TINY))
     toks = data.tokens(jax.random.key(2), (3, 9), 64)
     batch = {"inputs": toks[:, :-1], "labels": toks[:, 1:]}
-    loss, grads = ref.loss_and_grads(p, batch, TINY)
+    loss, grads = reference.loss_and_grads(ref.logits, p, batch, TINY)
 
     def whole(p):
         lg = jax.vmap(lambda x: ref.logits(p, x, TINY))(batch["inputs"])

@@ -1,7 +1,9 @@
-"""Cells of the benchmark cut to a tiny size, for rehearsals on the CPU.
+"""Cells of the benchmark cut to a tiny size, for rehearsals on the CPU: the
+model to its reference's ``TINY`` sizes, the traffic to a few short rows.
 
 The cells are BENCHMARK.json's and those of ``data/unlisted_cells.json``:
-cells whose files are here but which are not yet measured on the chip."""
+cells whose files are here but which are not yet measured on the chip (none,
+when its lists are empty)."""
 from __future__ import annotations
 
 import copy
@@ -11,8 +13,6 @@ from pathlib import Path
 
 from harness import core
 
-TINY_MODEL = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-              "num_attention_heads": 4, "num_key_value_heads": 2, "vocab_size": 256}
 UNLISTED = Path(__file__).resolve().parent / "data" / "unlisted_cells.json"
 
 
@@ -32,7 +32,7 @@ def tiny_cell(name: str, bench_file=None, **traffic) -> core.Cell:
     cell = (core.find_cell(name, bench_file) if bench_file
             else core.cell_from(bench(), core.CHECKOUT, name))
     cell.config = copy.deepcopy(cell.config)
-    cell.config["model"].update(TINY_MODEL)
+    cell.config["model"].update(cell.reference().TINY)
     cell.traffic = dict(cell.traffic, **traffic)
     small = {"batch": 2 * cell.chips, "seq": 32, "prompt": 16, "gen": 4, "requests": 2}
     for k, v in small.items():
